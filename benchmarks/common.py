@@ -47,8 +47,8 @@ def build(dataset: str, K: int, batch_total: int = 400, seed: int = 0):
 
 def provenance() -> dict:
     """Attribution stamp for a BENCH record: git sha (+dirty flag), jax
-    version, device kind, UTC timestamp. Every value degrades to a string
-    placeholder rather than failing — benches must run outside git too."""
+    version, device kind, UTC timestamp. The git sha degrades to
+    ``"unknown"`` outside a git checkout; a device JAX cannot name fails."""
     import jax
     sha = "unknown"
     try:
@@ -65,14 +65,10 @@ def provenance() -> dict:
                 sha += "-dirty"
     except (OSError, subprocess.TimeoutExpired):
         pass
-    try:
-        device = jax.devices()[0].device_kind
-    except Exception:
-        device = "unknown"
     return {
         "git_sha": sha,
         "jax_version": jax.__version__,
-        "device_kind": device,
+        "device_kind": jax.devices()[0].device_kind,
         "backend": jax.default_backend(),
         "timestamp_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }

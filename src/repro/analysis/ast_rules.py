@@ -36,7 +36,7 @@ import os
 from repro.analysis.findings import Finding
 
 TRACING_FUNCS = frozenset({
-    "jit", "scan", "vmap", "pmap", "shard_map", "shard_map_compat",
+    "jit", "scan", "vmap", "pmap", "shard_map",
     "cond", "switch", "while_loop", "fori_loop", "checkpoint", "remat",
     "grad", "value_and_grad", "jacfwd", "jacrev", "hessian",
     "eval_shape", "make_jaxpr", "custom_jvp", "custom_vjp",

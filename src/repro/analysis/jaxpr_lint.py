@@ -27,6 +27,8 @@ the max over branches, not the sum.
 **DEAD_CARRY.** A scan carry position whose body invar is returned unchanged
 and never read by any equation is dead state — copied through every
 iteration of the fused chunk for nothing, and usually a forgotten update.
+``jax.lax.scan`` hoists such a carry into a const before the jaxpr exists,
+so a scan const the body never reads is flagged the same way.
 
 **DTYPE_WIDEN.** Inside scan bodies only: an equation whose floating output
 is strictly wider than every floating input silently multiplies the hot
@@ -41,7 +43,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend.core import Literal
 
 from repro.analysis.findings import Finding
 
@@ -168,11 +170,11 @@ class _Analyzer:
         invariant.update(jaxpr.constvars)
 
         def is_invariant(v):
-            return isinstance(v, jcore.Literal) or find(v) in {
+            return isinstance(v, Literal) or find(v) in {
                 find(x) for x in invariant}
 
         def consume(v, eqn, how):
-            if isinstance(v, jcore.Literal):
+            if isinstance(v, Literal):
                 return
             r = find(v)
             counts[r] += 1
@@ -189,7 +191,7 @@ class _Analyzer:
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
             real_invars = [v for v in eqn.invars
-                           if not isinstance(v, jcore.Literal)]
+                           if not isinstance(v, Literal)]
             used.update(real_invars)
 
             if prim in ALIAS_PRIMS and real_invars and eqn.outvars:
@@ -303,6 +305,17 @@ class _Analyzer:
                     f"scan carry {j} ({aval.dtype}{list(aval.shape)}) is "
                     "passed through unchanged and never read by the body",
                     eqn)
+        # jax.lax.scan hoists a carry the body returns unchanged into a
+        # const, so a dead carry reaches the jaxpr as a const nothing reads
+        for i in range(num_consts):
+            in_v = body.invars[i]
+            if in_v not in body_used and in_v not in body.outvars:
+                aval = in_v.aval
+                self._emit(
+                    "DEAD_CARRY",
+                    f"scan input {i} ({aval.dtype}{list(aval.shape)}) is "
+                    "never read by the body (a carry returned unchanged, "
+                    "hoisted to a loop invariant)", eqn)
 
     def _while(self, eqn, counts, find, consume):
         cn = eqn.params["cond_nconsts"]
@@ -341,7 +354,7 @@ class _Analyzer:
         used = set()
         for eqn in jaxpr.eqns:
             used.update(v for v in eqn.invars
-                        if not isinstance(v, jcore.Literal))
+                        if not isinstance(v, Literal))
         return used
 
 

@@ -67,8 +67,11 @@ def replicate(tree: Tree, K: int, sharding=None) -> Tree:
     ``sharding`` (e.g. a ``NamedSharding`` over the node axis of a mesh)
     places every stacked leaf at creation time, so mesh runs start node-
     sharded instead of being resharded at the first jit boundary."""
-    out = jax.tree.map(lambda a: jnp.broadcast_to(a[None], (K,) + a.shape),
-                       tree)
-    if sharding is not None:
-        out = jax.tree.map(lambda a: jax.device_put(a, sharding), out)
-    return out
+    def stack(t):
+        return jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (K,) + a.shape), t)
+
+    if sharding is None:
+        return stack(tree)
+    # built in place: no device ever holds all K copies
+    return jax.jit(stack, out_shardings=sharding)(tree)

@@ -147,10 +147,7 @@ def ring_wmi_local(axis_name: str, self_weight: float = 1.0 / 3.0,
     nb = (1.0 - self_weight) / 2.0
 
     def apply(tree):
-        n = size
-        if n is None:
-            from repro.core.tracking import _axis_size
-            n = _axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name) if size is None else size
         to_left = [(i, (i - 1) % n) for i in range(n)]
         to_right = [(i, (i + 1) % n) for i in range(n)]
 

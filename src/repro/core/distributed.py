@@ -24,7 +24,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.common import HParams
-from repro.core.engine import ALGORITHMS, make_mix, shard_map_compat
+from repro.core.engine import ALGORITHMS, make_mix
 from repro.core.hypergrad import HypergradConfig
 from repro.core.problems import BilevelProblem
 
@@ -45,7 +45,8 @@ def make_distributed_step(problem: BilevelProblem, hcfg: HypergradConfig,
     spec = P(axis_name)  # prefix pytree: every leaf node-sharded on dim 0
 
     def step(state, batch, keys):
-        return shard_map_compat(inner, mesh, (spec, spec, spec), spec)(
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)(
             state, batch, keys)
 
     return jax.jit(step)
@@ -62,7 +63,8 @@ def make_distributed_init(problem: BilevelProblem, hcfg: HypergradConfig,
     spec = P(axis_name)
 
     def init(X0, Y0, batch, keys):
-        return shard_map_compat(inner, mesh, (spec, spec, spec, spec), spec)(
+        return jax.shard_map(inner, mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=spec, check_vma=False)(
             X0, Y0, batch, keys)
 
     return jax.jit(init)

@@ -88,19 +88,6 @@ from repro.core.tracking import (MixFn, dense_mix, param_update,
 
 Tree = Any
 
-try:  # jax >= 0.6 promotes shard_map; the kwarg was renamed check_rep->check_vma
-    _shard_map, _SM_NOCHECK = jax.shard_map, {"check_vma": False}
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_SM_NOCHECK)
-
-
 # ---------------------------------------------------------------------------
 # Sampler protocol
 # ---------------------------------------------------------------------------
@@ -524,7 +511,8 @@ class Engine:
         if not self._shard_local:
             return fn
         spec = P(self.axis_name)
-        return shard_map_compat(fn, self.mesh, (spec,) * n_in, spec)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=(spec,) * n_in,
+                             out_specs=spec, check_vma=False)
 
     def _cached(self, name: str, build: Callable):
         if name not in self._jit_cache:
@@ -586,8 +574,9 @@ class Engine:
                 return jax.lax.scan(body, carry, (batches, nkeys))[0]
 
             spec, tspec = P(self.axis_name), P(None, self.axis_name)
-            chunk = shard_map_compat(chunk, self.mesh,
-                                     (spec, tspec, tspec), spec)
+            chunk = jax.shard_map(chunk, mesh=self.mesh,
+                                  in_specs=(spec, tspec, tspec),
+                                  out_specs=spec, check_vma=False)
             return jax.jit(chunk, donate_argnums=self._donate)
 
         def obs_body(cm, batch, nkeys):
@@ -697,6 +686,7 @@ class Engine:
         kb0, kn0 = jax.random.split(k0)  # independent batch / J̃ init keys
         b0, nk0 = sample_batch(kb0), jax.random.split(kn0, K)
         state = self.init(X0, Y0, b0, nk0)
+        del X0, Y0   # a model-sized copy per node: free it for the run
         carry = ((state, self._mix_state0(state, b0, nk0))
                  if self._mix_stateful else state)
         kbs, kns = key_schedule(key, steps)

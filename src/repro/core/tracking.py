@@ -40,14 +40,6 @@ def dense_mix(W) -> MixFn:
     return mix
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size, portable across jax versions."""
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)  # jax 0.4.x returns a bare int
-    return frame if isinstance(frame, int) else frame.size
-
-
 def ring_mix_local(axis_name: str, self_weight: float = 1.0 / 3.0,
                    size: int | None = None) -> MixFn:
     """Ring mixing *inside* shard_map: node axis is the mesh axis ``axis_name``
@@ -57,7 +49,7 @@ def ring_mix_local(axis_name: str, self_weight: float = 1.0 / 3.0,
     nb = (1.0 - self_weight) / 2.0
 
     def mix(tree):
-        n = _axis_size(axis_name) if size is None else size
+        n = jax.lax.axis_size(axis_name) if size is None else size
         left = [(i, (i - 1) % n) for i in range(n)]
         right = [(i, (i + 1) % n) for i in range(n)]
 

@@ -3,7 +3,7 @@ over that slot's KV pages *through its block table* — the physical pool is
 never materialized into a per-slot dense logical cache.
 
 TPU-native design notes (vs the dense ``_flash_kernel``):
-  * Grid is (B, Hkv, n_pages) with the page dimension innermost — the
+  * Grid is (B, n_pages) with the page dimension innermost — the
     online-softmax running state (m, l, acc) lives in VMEM scratch persisting
     across a slot's pages, exactly like the k-block dimension of the flash
     kernel.
@@ -11,6 +11,12 @@ TPU-native design notes (vs the dense ``_flash_kernel``):
     (``pltpu.PrefetchScalarGridSpec``): the k/v BlockSpec index_map reads
     ``tables[b, j]`` to aim each page DMA at a physical block, so only the
     pages a slot actually owns are ever pulled from HBM.
+  * One page DMA carries every KV head: the K/V block is
+    ``(1, block_size, 1, Hkv, Dh)``, whose two minor dims are the full pool
+    dims — the TPU tiling rule (minor dims divisible by (8, 128) or equal to
+    the array's) refuses a one-head block once Hkv is not a multiple of 8
+    (smollm-360m: Hkv=5), and Mosaic cannot prove a grid-indexed head offset
+    aligned. The kernel walks the heads with static indices instead.
   * Pages past a slot's used length are clamped to the *last valid* page in
     the index_map — consecutive grid steps with an unchanged block index skip
     the DMA (TPU revolving-buffer rule), so dead/out-of-range pages cost
@@ -19,9 +25,9 @@ TPU-native design notes (vs the dense ``_flash_kernel``):
     ``pos < length`` mask zeroes the unwritten lanes, which is what keeps
     trash-block garbage (dead slots, unallocated table entries) out of every
     result.
-  * GQA is native: the grid iterates KV heads and each program computes all
-    ``G = H // Hkv`` grouped query heads against one loaded page, so grouped
-    configs serve without replicating K/V.
+  * GQA is native: each KV head's ``G = H // Hkv`` grouped query heads are
+    computed against the loaded page, so grouped configs serve without
+    replicating K/V.
 
 The pool layout matches ``repro.serve.batch.BlockPool`` for attention
 families: ``[num_blocks + 1, block_size, L, Hkv, Dh]`` with the trailing
@@ -29,7 +35,8 @@ trash block at index ``num_blocks``; ``layer`` selects the transformer layer
 so the serving layer-scan calls the kernel without slicing the pool.
 
 Validated on CPU with interpret=True against
-``repro.kernels.ref.paged_attention_ref`` (tests/test_kernels.py).
+``repro.kernels.ref.paged_attention_ref`` (tests/test_kernels.py); compiled
+for a described TPU v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -45,59 +52,14 @@ NEG_INF = -1e30
 
 def _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref, v_ref,
                   o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                  block_size: int, n_pages: int):
+                  block_size: int, n_pages: int, q_len: int, group: int,
+                  n_kv_heads: int):
+    """Q query rows per slot and KV head: the flattened [Q*G, ...] row axis
+    carries both the window position (row // G) and the grouped query head
+    (row % G). Single-token decode is the Q=1 case, whose per-row causal
+    mask reduces to the tail-block mask ``pos < length``."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = lengths_ref[b]
-
-    # page-level pruning: a page whose first position is past the slot's used
-    # length holds nothing valid (dead slots have length 0 — every page skips)
-    @pl.when(j * block_size < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # [G, Dh]
-        k = k_ref[0, :, 0, 0].astype(jnp.float32)            # [bs, Dh]
-        v = v_ref[0, :, 0, 0].astype(jnp.float32)            # [bs, Dh]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, bs]
-
-        # tail-block mask: only positions the slot has actually written
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-
-        m_prev = m_scr[...]                                  # [G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                               # [G, bs]
-        alpha = jnp.exp(m_prev - m_new)                      # [G, 1]
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)   # dead slot: emit zeros, not NaN
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-def _paged_multi_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref,
-                        v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                        block_size: int, n_pages: int, q_len: int, group: int):
-    """Q query rows per slot: the flattened [Q*G, ...] row axis carries both
-    the window position (row // G) and the grouped query head (row % G); the
-    per-row causal mask is the only place the two kernels differ."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -111,34 +73,70 @@ def _paged_multi_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref,
     # a page past it holds nothing any row may read (dead slots: length 0)
     @pl.when(j * block_size < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # [Q*G, Dh]
-        k = k_ref[0, :, 0, 0].astype(jnp.float32)            # [bs, Dh]
-        v = v_ref[0, :, 0, 0].astype(jnp.float32)            # [bs, Dh]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        for h in range(n_kv_heads):   # static head index: see module notes
+            q = q_ref[0, h].astype(jnp.float32) * scale      # [Q*G, Dh]
+            k = k_ref[0, :, 0, h].astype(jnp.float32)        # [bs, Dh]
+            v = v_ref[0, :, 0, h].astype(jnp.float32)        # [bs, Dh]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
 
-        # per-row causal mask: row r (window position r = flat // G) attends
-        # positions < length - (Q - 1 - r); the tail-block mask is subsumed
-        pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        s = jnp.where(pos < length - (q_len - 1 - row), s, NEG_INF)
+            # per-row causal mask: row r (window position r = flat // G)
+            # attends positions < length - (Q - 1 - r); it subsumes the
+            # tail-block mask
+            pos = j * block_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            s = jnp.where(pos < length - (q_len - 1 - row), s, NEG_INF)
 
-        m_prev = m_scr[...]                                  # [Q*G, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                               # [Q*G, bs]
-        alpha = jnp.exp(m_prev - m_new)                      # [Q*G, 1]
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+            m_prev = m_scr[h]                                # [Q*G, 1]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)                           # [Q*G, bs]
+            alpha = jnp.exp(m_prev - m_new)                  # [Q*G, 1]
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(j == n_pages - 1)
     def _finalize():
-        l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked row: zeros, not NaN
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for h in range(n_kv_heads):
+            l = l_scr[h]
+            l = jnp.where(l == 0.0, 1.0, l)   # fully-masked row: zeros, not NaN
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
+
+
+def _grid_spec(B, n_pages, rows, Hkv, Dh, block_size):
+    """Grid (slot, page) over q/o blocks of one slot's ``[Hkv, rows, Dh]``
+    and K/V blocks of one page's ``[block_size, 1, Hkv, Dh]``."""
+
+    def kv_map(b, j, tables, lengths, layer):
+        # out-of-range pages re-target the slot's last valid page (the LAST
+        # row's reach bounds every row's): the block index is unchanged from
+        # the previous grid step, so the DMA is skipped (compute is pruned by
+        # pl.when on the same predicate)
+        last = jnp.maximum(lengths[b] - 1, 0) // block_size
+        return (tables[b, jnp.minimum(j, last)], 0, layer[0], 0, 0)
+
+    def q_map(b, j, *refs):
+        return (b, 0, 0, 0)
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, n_pages),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, rows, Dh), q_map),
+            pl.BlockSpec((1, block_size, 1, Hkv, Dh), kv_map),
+            pl.BlockSpec((1, block_size, 1, Hkv, Dh), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, rows, Dh), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # running max m
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((Hkv, rows, Dh), jnp.float32),   # fp32 accumulator
+        ],
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -165,42 +163,16 @@ def paged_attention_multi(q, k_pages, v_pages, tables, lengths, layer=0, *,
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
     n_pages = tables.shape[1]
-    scale = Dh ** -0.5
     # [B, Q, Hkv, G, Dh] -> [B, Hkv, Q*G, Dh]: rows ordered window-major so
     # the kernel recovers the window position as row // G
     q4 = q.reshape(B, Q, Hkv, G, Dh).transpose(0, 2, 1, 3, 4).reshape(
         B, Hkv, Q * G, Dh)
-
-    def kv_map(b, h, j, tables, lengths, layer):
-        # same DMA-skip clamp as the single-token kernel: the LAST row's
-        # reach bounds every row's, so pages past it re-target the last
-        # valid page and their (pruned) step skips the copy
-        last = jnp.maximum(lengths[b] - 1, 0) // block_size
-        return (tables[b, jnp.minimum(j, last)], 0, layer[0], h, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, Q * G, Dh),
-                         lambda b, h, j, *refs: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, 1, Dh), kv_map),
-            pl.BlockSpec((1, block_size, 1, 1, Dh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Q * G, Dh),
-                               lambda b, h, j, *refs: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Q * G, 1), jnp.float32),    # running max m
-            pltpu.VMEM((Q * G, 1), jnp.float32),    # running denom l
-            pltpu.VMEM((Q * G, Dh), jnp.float32),   # fp32 accumulator
-        ],
-    )
-    kernel = functools.partial(_paged_multi_kernel, scale=scale,
+    kernel = functools.partial(_paged_kernel, scale=Dh ** -0.5,
                                block_size=block_size, n_pages=n_pages,
-                               q_len=Q, group=G)
+                               q_len=Q, group=G, n_kv_heads=Hkv)
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=_grid_spec(B, n_pages, Q * G, Hkv, Dh, block_size),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Q * G, Dh), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
@@ -230,39 +202,15 @@ def paged_attention(q, k_pages, v_pages, tables, lengths, layer=0, *,
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
     n_pages = tables.shape[1]
-    scale = Dh ** -0.5
-    q4 = q.reshape(B, Hkv, G, Dh)
-
-    def kv_map(b, h, j, tables, lengths, layer):
-        # out-of-range pages re-target the slot's last valid page: the block
-        # index is unchanged from the previous grid step, so the DMA is
-        # skipped (compute is pruned by pl.when on the same predicate)
-        last = jnp.maximum(lengths[b] - 1, 0) // block_size
-        return (tables[b, jnp.minimum(j, last)], 0, layer[0], h, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, *refs: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, 1, Dh), kv_map),
-            pl.BlockSpec((1, block_size, 1, 1, Dh), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, Dh),
-                               lambda b, h, j, *refs: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # running max m
-            pltpu.VMEM((G, 1), jnp.float32),    # running denom l
-            pltpu.VMEM((G, Dh), jnp.float32),   # fp32 accumulator
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, scale=scale,
-                               block_size=block_size, n_pages=n_pages)
+    kernel = functools.partial(_paged_kernel, scale=Dh ** -0.5,
+                               block_size=block_size, n_pages=n_pages,
+                               q_len=1, group=G, n_kv_heads=Hkv)
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
+        grid_spec=_grid_spec(B, n_pages, G, Hkv, Dh, block_size),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q4, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(B, Hkv, G, Dh),
+      k_pages, v_pages)
     return out.reshape(B, H, Dh)

@@ -241,8 +241,6 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     compile_s = time.time() - t0
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 wraps the dict in a list
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)  # per-op-kind, unmultiplied (reference)

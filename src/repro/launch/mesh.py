@@ -5,13 +5,12 @@ from __future__ import annotations
 import jax
 
 
-def _mesh(shape, axes, devices):
-    """jax.make_mesh across versions: axis_types only exists on jax >= 0.5."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+def make_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis in Auto mode, so GSPMD places what
+    the program does not pin (Explicit axes make vmap and friends demand
+    matching shardings)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -29,7 +28,7 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices, have {len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return _mesh(shape, axes, devices[:n])
+    return make_mesh(shape, axes, devices[:n])
 
 
 def make_debug_mesh(*, multi_pod: bool = False, data: int = 2, model: int = 2):
@@ -39,4 +38,4 @@ def make_debug_mesh(*, multi_pod: bool = False, data: int = 2, model: int = 2):
     n = 1
     for s in shape:
         n *= s
-    return _mesh(shape, axes, jax.devices()[:n])
+    return make_mesh(shape, axes, jax.devices()[:n])

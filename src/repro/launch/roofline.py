@@ -1,9 +1,8 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Hardware model (TPU v5e target):
-    peak compute  197 TFLOP/s bf16 per chip
-    HBM bandwidth 819 GB/s per chip
-    ICI link      ~50 GB/s per link
+Hardware model: the per-chip peaks of :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports. The dry-run targets a TPU v5e pod
+(:data:`TARGET_KIND`).
 
 ``compiled.cost_analysis()`` on a GSPMD-partitioned module reports PER-DEVICE
 flops / bytes (verified empirically), so the three terms are
@@ -22,9 +21,32 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
-HBM_BW = 819e9            # B/s per chip
-LINK_BW = 50e9            # B/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM B/s per chip
+    link_bw: float    # B/s per ICI link
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e (reported as "TPU v5 lite"): Google Cloud documentation, "TPU v5e"
+# — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI per chip over 4 links.
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=1600e9 / 8 / 4),
+}
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a device not in :data:`PEAKS` is an
+    error, not a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; have {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -78,18 +100,20 @@ class Roofline:
     flops_per_device: float
     hbm_bytes_per_device: float
     collective_bytes_per_device: float
+    device_kind: str = TARGET_KIND
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_device / HBM_BW
+        return self.hbm_bytes_per_device / peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / LINK_BW
+        return (self.collective_bytes_per_device
+                / peaks(self.device_kind).link_bw)
 
     @property
     def dominant(self) -> str:
@@ -103,6 +127,7 @@ class Roofline:
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
             "dominant": self.dominant,
+            "device_kind": self.device_kind,
             "flops_per_device": self.flops_per_device,
             "hbm_bytes_per_device": self.hbm_bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,

@@ -10,6 +10,9 @@ cohort drain stay available for comparison:
       --block-size 8 --num-blocks 16
   python -m repro.launch.serve --arch smollm-360m --reduced --mode paged \
       --block-size 8 --kv-impl pallas   # force the kernel (interpret on CPU)
+
+Prompts are random tokens, between a quarter and a half of ``--capacity``
+long (at least 3), and the weights are random from a fixed seed.
 """
 from __future__ import annotations
 
@@ -20,12 +23,15 @@ import jax
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.obs import cli_recorder
 from repro.serve import ServeEngine
 
 
-def main():
+def main(argv=None):
+    """Serve the requests; returns ``(engine, {rid: tokens})`` so an
+    in-process caller can inspect what ran."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -53,7 +59,8 @@ def main():
                     help="write metrics.jsonl + metrics.prom into DIR")
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
                     help="write a Perfetto-loadable trace.json into DIR")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     spec = get(args.arch)
     cfg = spec.reduced() if args.reduced else spec.config
@@ -65,8 +72,10 @@ def main():
                       block_size=args.block_size, num_blocks=args.num_blocks,
                       kv_impl=args.kv_impl, recorder=recorder)
     rng = np.random.default_rng(0)
+    lo = max(3, args.capacity // 4)
+    hi = max(lo, min(args.capacity // 2, args.capacity - args.max_new))
     for i in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab, size=rng.integers(3, 10))
+        prompt = rng.integers(0, cfg.vocab, size=rng.integers(lo, hi + 1))
         eng.submit(prompt, max_new_tokens=args.max_new)
     t0 = time.time()
     results = eng.run()
@@ -81,6 +90,7 @@ def main():
         print("  " + ", ".join(f"{k}={v}" for k, v in eng.stats.items()))
     for p in finalize_obs():
         print("obs:", p)
+    return eng, results
 
 
 if __name__ == "__main__":

@@ -210,7 +210,8 @@ def sample_batch(k):
             "h": jax.vmap(lambda kk: jax.random.split(kk, J))(
                 jax.random.split(kh, K))}
 
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",), jax.devices())
 
 def leaves_equal(a, b):
     return all(np.array_equal(np.asarray(x), np.asarray(y))

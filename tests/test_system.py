@@ -100,13 +100,16 @@ def test_collective_parser_on_synthetic_hlo():
 
 
 def test_roofline_terms_and_dominance():
-    from repro.launch.roofline import HBM_BW, LINK_BW, PEAK_FLOPS, Roofline
-    rl = Roofline(flops_per_device=PEAK_FLOPS, hbm_bytes_per_device=HBM_BW,
-                  collective_bytes_per_device=2 * LINK_BW)
+    from repro.launch.roofline import TARGET_KIND, Roofline, peaks
+    pk = peaks(TARGET_KIND)
+    rl = Roofline(flops_per_device=pk.flops, hbm_bytes_per_device=pk.hbm_bw,
+                  collective_bytes_per_device=2 * pk.link_bw)
     assert rl.t_compute == pytest.approx(1.0)
     assert rl.t_memory == pytest.approx(1.0)
     assert rl.t_collective == pytest.approx(2.0)
     assert rl.dominant == "collective"
+    with pytest.raises(ValueError, match="no published peaks"):
+        _ = Roofline(1.0, 1.0, 1.0, device_kind="cpu").t_compute
 
 
 def test_model_flops_accounting():
