@@ -1,0 +1,11 @@
+"""Share of the traced window in which no operation ran on the chip:
+1 - (union of device operation intervals) / window."""
+from bench import readings
+
+KIND, UNIT = "per_layer", "%"
+
+
+def read(records, red):
+    if red is None or not readings.serve_records(records):
+        return None
+    return 100.0 * red["idle_share"]

@@ -1,0 +1,10 @@
+"""The bench tests run on the CPU: ``PYTHONPATH=src python -m pytest bench/tests``."""
+import os
+import pathlib
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(_ROOT / "src"), str(_ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
