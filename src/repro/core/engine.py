@@ -670,6 +670,11 @@ class Engine:
         *global* batch for diagnostics. ``on_eval(t, state)`` fires after
         every recorded eval boundary (t=0 included) — the checkpointing hook
         used by ``repro.launch.train``.
+
+        The recorder's spans cover the host's work: ``engine.dispatch``
+        (key slice and the chunk or step call), ``engine.drain`` (in-scan
+        metric drain), ``engine.eval`` (evaluate and its host reads) with
+        ``engine.on_eval`` (the callback) inside it.
         """
         del init_batch_scale  # accepted for API compatibility
         K = self.K
@@ -693,10 +698,11 @@ class Engine:
 
         in_scan = self.dispatch == "fused" and not self._shard_local
         rec = self.recorder
-        # In-scan metric accumulation rides the fused chunk only; per_step
-        # and shard_local dispatch record eval-boundary gauges alone (metric
-        # reduction out of shard_map is out of scope — documented in
-        # docs/observability.md).
+        # In-scan metric accumulation rides the fused chunk only, and only
+        # when the recorder records metrics: a trace-only recorder runs the
+        # chunk an un-instrumented engine runs. per_step and shard_local
+        # dispatch record eval-boundary gauges alone (metric reduction out
+        # of shard_map is out of scope — docs/observability.md).
         obs_in_scan = in_scan and rec.enabled
         mset = self._obs_mset(state, b0, nk0) if obs_in_scan else None
         obs_in_scan = obs_in_scan and len(mset) > 0
@@ -704,7 +710,7 @@ class Engine:
         t0 = time.perf_counter()
 
         def record(t, state, trace=None):
-            with rec.span("eval", step=t):
+            with rec.span("engine.eval", step=t):
                 m = self.evaluate(state, eval_batch)
                 res.steps.append(t)
                 res.upper_loss.append(float(m["upper"]))
@@ -731,14 +737,16 @@ class Engine:
                                  **{f"eval_{k}": float(v)
                                     for k, v in extras.items()}}, step=t)
                 if on_eval is not None:
-                    on_eval(t, state)
+                    with rec.span("engine.on_eval", step=t):
+                        on_eval(t, state)
 
         record(0, self._carry_state(carry))
 
         if self.dispatch == "per_step":
             for t in range(1, steps + 1):
-                carry = self.step(carry, sample_batch(kbs[t - 1]),
-                                  jax.random.split(kns[t - 1], K))
+                with rec.span("engine.dispatch", step=t):
+                    carry = self.step(carry, sample_batch(kbs[t - 1]),
+                                      jax.random.split(kns[t - 1], K))
                 if t % eval_every == 0 or t == steps:
                     rec.counter_add("train_steps", eval_every
                                     if t % eval_every == 0 else t % eval_every)
@@ -750,8 +758,8 @@ class Engine:
             t = 0
             while t < steps:
                 n = min(eval_every, steps - t)
-                kb_c, kn_c = kbs[t:t + n], kns[t:t + n]
-                with rec.span("train_chunk", t0=t, steps=n):
+                with rec.span("engine.dispatch", step=t, steps=n):
+                    kb_c, kn_c = kbs[t:t + n], kns[t:t + n]
                     if self._shard_local:
                         xs = self._stack_batches(sample_batch, kb_c, host)
                         nk = jax.vmap(lambda k: jax.random.split(k, K))(kn_c)
@@ -773,8 +781,9 @@ class Engine:
                     # drain at the chunk boundary (the host is already
                     # syncing for the eval record below) and reset the
                     # accumulator for the next chunk
-                    rec.record_drain(mset.drain(macc), step=t)
-                    macc = mset.init()
+                    with rec.span("engine.drain", step=t):
+                        rec.record_drain(mset.drain(macc), step=t)
+                        macc = mset.init()
                 record(t, self._carry_state(carry), trace)
 
         res.wall_time_s = time.perf_counter() - t0

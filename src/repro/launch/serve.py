@@ -10,6 +10,8 @@ cohort drain stay available for comparison:
       --block-size 8 --num-blocks 16
   python -m repro.launch.serve --arch smollm-360m --reduced --mode paged \
       --block-size 8 --kv-impl pallas   # force the kernel (interpret on CPU)
+  python -m repro.launch.serve --arch smollm-360m --reduced --mode paged \
+      --trace-dir out/obs --jax-profile   # serve.* spans beside the device
 
 Prompts are random tokens, between a quarter and a half of ``--capacity``
 long (at least 3), and the weights are random from a fixed seed.
@@ -25,7 +27,7 @@ import numpy as np
 from repro.configs import get
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
-from repro.obs import cli_recorder
+from repro.obs import cli_recorder, jax_profile
 from repro.serve import ServeEngine
 
 
@@ -59,7 +61,12 @@ def main(argv=None):
                     help="write metrics.jsonl + metrics.prom into DIR")
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
                     help="write a Perfetto-loadable trace.json into DIR")
+    ap.add_argument("--jax-profile", action="store_true",
+                    help="additionally capture a jax.profiler device trace "
+                         "of the drain into --trace-dir")
     args = ap.parse_args(argv)
+    if args.jax_profile and not args.trace_dir:
+        raise SystemExit("--jax-profile needs --trace-dir")
     use_compile_cache()
 
     spec = get(args.arch)
@@ -78,7 +85,11 @@ def main(argv=None):
         prompt = rng.integers(0, cfg.vocab, size=rng.integers(lo, hi + 1))
         eng.submit(prompt, max_new_tokens=args.max_new)
     t0 = time.time()
-    results = eng.run()
+    if args.jax_profile:
+        with jax_profile(args.trace_dir):
+            results = eng.run()
+    else:
+        results = eng.run()
     dt = time.time() - t0
     total_toks = sum(len(v) for v in results.values())
     for rid, toks in sorted(results.items()):

@@ -7,17 +7,20 @@ A recorder is the single object both engines talk to:
   :meth:`Recorder.record_drain` once per fused chunk and pushes eval-boundary
   ``RunResult`` metrics through :meth:`Recorder.metrics`;
 * the **serving** :class:`~repro.serve.engine.ServeEngine` feeds counters
-  (prefills, preemptions, admission rejects, emitted tokens), gauges (queue
-  depth, free blocks) and latency observations (TTFT, inter-token) at the
-  chunk boundaries it already crosses.
+  (submissions, admission rejects, and each drain's stats), peak gauges and
+  latency observations (queue wait, TTFT, inter-token) at the chunk
+  boundaries it already crosses.
 
 Everything is plain Python/numpy — nothing here is ever traced, and the hot
 path never blocks on it: the engines only call in at chunk boundaries, where
 they already touch the host.
 
 :class:`NullRecorder` is the default everywhere and makes every call a
-no-op, so observability costs nothing when off (the obs-overhead row in
-``benchmarks/serve_bench.py`` pins the enabled cost too).
+no-op; each instrumented call site then costs one null context manager.
+``Recorder(tracer=SpanTracer(), metrics=False)`` only traces: its spans go
+to the tracer (and so into any active profiler session) and it records no
+metric, so ``enabled`` is False and the engines compile and run the same
+device programs as with the NullRecorder.
 
 Exports: an append-only **JSONL event log** (one JSON object per line, every
 ``event()``/``metrics()``/``record_drain()`` call), a **Prometheus
@@ -56,7 +59,11 @@ def _json_default(o):
 class NullRecorder:
     """The default: observability off. Every method is a no-op; ``span``
     returns a shared null context manager so instrumented call sites cost a
-    dict lookup and nothing else."""
+    dict lookup and nothing else.
+
+    ``enabled`` means "records metrics": the engines read it to decide
+    whether to thread the in-scan metric set through the training chunk and
+    to build per-request and per-drain records."""
 
     enabled = False
     tracer = None
@@ -103,12 +110,14 @@ class NullRecorder:
 
 class Recorder(NullRecorder):
     """Live recorder: counters / gauges / observations / histograms in
-    process, optional JSONL event log, optional :class:`SpanTracer`."""
+    process, optional JSONL event log, optional :class:`SpanTracer`.
 
-    enabled = True
+    ``metrics=False`` keeps only the tracer: every metric call is a no-op
+    and ``enabled`` is False (a trace-only recorder)."""
 
     def __init__(self, jsonl_path: str | None = None, tracer=None,
-                 max_observations: int = 100_000):
+                 max_observations: int = 100_000, *, metrics: bool = True):
+        self.enabled = bool(metrics)
         self.tracer = tracer
         self._jsonl_path = jsonl_path
         self._jsonl = None
@@ -122,18 +131,26 @@ class Recorder(NullRecorder):
     # -- primitives ---------------------------------------------------------
 
     def counter_add(self, name, value=1.0, **labels):
+        if not self.enabled:
+            return
         k = _key(name, labels)
         self.counters[k] = self.counters.get(k, 0.0) + float(value)
 
     def gauge_set(self, name, value, **labels):
+        if not self.enabled:
+            return
         self.gauges[_key(name, labels)] = float(value)
 
     def observe(self, name, value, **labels):
+        if not self.enabled:
+            return
         vs = self.observations.setdefault(_key(name, labels), [])
         if len(vs) < self._max_obs:
             vs.append(float(value))
 
     def hist_add(self, name, counts, **labels):
+        if not self.enabled:
+            return
         k = _key(name, labels)
         c = np.asarray(counts, np.int64)
         if k in self.hist_counts:
@@ -171,6 +188,8 @@ class Recorder(NullRecorder):
         self.event("drain", step=step, **fields)
 
     def event(self, kind, **fields):
+        if not self.enabled:
+            return
         ev = {"ts": time.time(), "kind": kind}
         ev.update(fields)
         self.events.append(ev)
@@ -291,7 +310,9 @@ class Recorder(NullRecorder):
 def cli_recorder(metrics_dir: str | None = None,
                  trace_dir: str | None = None):
     """Build ``(recorder, finalize)`` from the launch CLIs' ``--metrics`` /
-    ``--trace-dir`` flags. Both unset -> :class:`NullRecorder` (zero cost).
+    ``--trace-dir`` flags. Both unset -> :class:`NullRecorder` (zero cost);
+    ``--trace-dir`` alone -> a trace-only recorder (no metric recorded, the
+    same device programs as with neither flag).
     ``finalize()`` writes the Prometheus snapshot (+ the Chrome trace when
     tracing), closes the JSONL log, and returns the list of paths written —
     what the CI smoke run uploads as artifacts."""
@@ -301,7 +322,8 @@ def cli_recorder(metrics_dir: str | None = None,
     tracer = SpanTracer() if trace_dir else None
     jsonl = (os.path.join(metrics_dir, "metrics.jsonl")
              if metrics_dir else None)
-    rec = Recorder(jsonl_path=jsonl, tracer=tracer)
+    rec = Recorder(jsonl_path=jsonl, tracer=tracer,
+                   metrics=metrics_dir is not None)
 
     def finalize() -> list[str]:
         paths = []

@@ -236,20 +236,21 @@ class ServeEngine:
                       submit_s=time.perf_counter())
         self.scheduler.submit(req)
         self.recorder.counter_add("serve_submitted")
-        self.recorder.instant("submit", rid=rid, prompt_len=len(prompt),
+        self.recorder.instant("serve.submit", rid=rid, prompt_len=len(prompt),
                               budget=max_new_tokens)
         return rid
 
     def _record_done(self, req: Request) -> None:
-        """Per-request latency accounting at completion: TTFT, end-to-end
-        latency, mean inter-token gap, and a ``request_done`` event keyed by
-        rid (what tests/test_serve_obs.py asserts against)."""
+        """Per-request latency accounting at completion: queue wait, TTFT,
+        end-to-end latency, mean inter-token gap, and a ``request_done``
+        event keyed by rid (what tests/test_serve_obs.py asserts against)."""
         rec = self.recorder
         if not rec.enabled:
             return
         ttft = (req.first_token_s - req.submit_s) if req.first_token_s else 0.0
         latency = (req.finish_s - req.submit_s) if req.finish_s else 0.0
         rec.counter_add("serve_finished")
+        rec.observe("serve_queue_s", req.admit_s - req.submit_s)
         rec.observe("serve_ttft_s", ttft)
         rec.observe("serve_latency_s", latency)
         if req.first_token_s and req.finish_s and len(req.output) > 1:
@@ -313,22 +314,32 @@ class ServeEngine:
             batch["length"] = jnp.asarray(length, jnp.int32)
         return batch
 
-    def _emit(self, reqs):
-        """Yield the not-yet-streamed suffix of each request's output.
+    def _deltas(self, reqs) -> list:
+        """The not-yet-streamed suffix of each request's output, as
+        ``(rid, delta, done)`` events.
 
         After a preemption the request regenerates its (bitwise identical)
         tokens from scratch; the per-rid high-water mark suppresses re-yields
         until the regeneration passes what was already streamed."""
+        out = []
         for req in reqs:
-            n = self._streamed.get(req.rid, 0)
-            delta = req.output[n:]
+            delta = req.output[self._streamed.get(req.rid, 0):]
             if delta:
-                self._streamed[req.rid] = n + len(delta)
-                yield req.rid, list(delta), req.done
+                out.append((req.rid, list(delta), req.done))
+        return out
+
+    def _emit(self, events):
+        """Yield ``events``, each marked streamed as it is handed over, so a
+        consumer that abandons the stream loses none. The loops build events
+        inside their spans and yield them outside: no span stays open while
+        the consumer runs."""
+        for rid, delta, done in events:
+            self._streamed[rid] = self._streamed.get(rid, 0) + len(delta)
+            yield rid, delta, done
 
     def _prefill_first_token(self, req: Request):
         """Run the admission prefill; returns (first_token, request cache)."""
-        with self.recorder.span("prefill", rid=req.rid,
+        with self.recorder.span("serve.prefill", rid=req.rid,
                                 prompt_len=len(req.prompt)):
             logits, req_cache = self._prefill(self.params,
                                               self._admission_batch(req))
@@ -352,7 +363,8 @@ class ServeEngine:
         live = np.zeros((B,), bool)
         remaining = np.zeros((B,), np.int32)
         stats = {"prefills": 0, "decode_dispatches": 0, "decode_steps": 0,
-                 "emitted_tokens": 0, "peak_concurrency": 0}
+                 "live_slot_steps": 0, "emitted_tokens": 0,
+                 "peak_concurrency": 0}
 
         def finish(i: int) -> Request:
             req = sched.release(i)
@@ -374,62 +386,62 @@ class ServeEngine:
 
     def _continuous_loop(self, sched, cache, tok, live, remaining, stats,
                          finish):
-        eos = self.eos_id
+        eos, rec, chunk = self.eos_id, self.recorder, self.decode_chunk
         while sched.has_work():
             # admission: prefill queued requests into free slots, mid-decode
             for i, req in sched.admit():
-                first, req_cache = self._prefill_first_token(req)
-                stats["prefills"] += 1
-                stats["emitted_tokens"] += 1  # the prefill-produced token
-                if req.add_token(first, eos):
-                    finish(i)   # prefill token was EOS or budget == 1
-                else:
-                    cache = self._write_slot(cache, req_cache,
-                                             jnp.asarray(i, jnp.int32))
-                    tok[i], live[i] = first, True
-                    remaining[i] = req.remaining
-                yield from self._emit([req])
+                with rec.span("serve.admit", rid=req.rid):
+                    if not req.admit_s:
+                        req.admit_s = time.perf_counter()
+                    first, req_cache = self._prefill_first_token(req)
+                    stats["prefills"] += 1
+                    stats["emitted_tokens"] += 1  # the prefill's token
+                    if req.add_token(first, eos):
+                        finish(i)   # prefill token was EOS or budget == 1
+                    else:
+                        cache = self._write_slot(cache, req_cache,
+                                                 jnp.asarray(i, jnp.int32))
+                        tok[i], live[i] = first, True
+                        remaining[i] = req.remaining
+                    out = self._deltas([req])
+                yield from self._emit(out)
             stats["peak_concurrency"] = max(stats["peak_concurrency"],
                                             len(sched.occupied()))
-            self._boundary_gauges(stats)
             if not live.any():
                 continue  # queue may still hold work; otherwise loop exits
-            with self.recorder.span("decode_chunk", steps=self.decode_chunk):
+            with rec.span("serve.decode_dispatch", steps=chunk):
+                stats["live_slot_steps"] += int(live.sum()) * chunk
                 out = self._fused_decode(
                     self.params, jnp.asarray(tok), cache,
                     jnp.asarray(live), jnp.asarray(remaining))
-            tok_d, cache, live_d, remaining_d, tokens, emitted = out
-            # in place: finish() closes over these same arrays
-            tok[:], live[:] = np.asarray(tok_d), np.asarray(live_d)
-            remaining[:] = np.asarray(remaining_d)
+            with rec.span("serve.decode_wait"):
+                tok_d, cache, live_d, remaining_d, tokens, emitted = out
+                # in place: finish() closes over these same arrays
+                tok[:], live[:] = np.asarray(tok_d), np.asarray(live_d)
+                remaining[:] = np.asarray(remaining_d)
+                tokens, emitted = np.asarray(tokens), np.asarray(emitted)
             stats["decode_dispatches"] += 1
-            stats["decode_steps"] += self.decode_chunk
-            stats["emitted_tokens"] += int(np.asarray(emitted).sum())
-            reqs = [r for _, r in sched.occupied()]
-            for i in sched.record_decode(tokens, emitted, eos):
-                finish(i)
-            yield from self._emit(reqs)
-
-    def _boundary_gauges(self, stats: dict) -> None:
-        """Chunk-boundary gauges: queue depth, concurrency, pool occupancy."""
-        rec = self.recorder
-        if not rec.enabled:
-            return
-        rec.gauge_set("serve_queue_depth", len(self.scheduler.queue))
-        rec.gauge_set("serve_concurrency", len(self.scheduler.occupied()))
-        if self.pool is not None:
-            rec.gauge_set("serve_free_blocks", self.pool.free_blocks)
-            rec.gauge_set("serve_block_occupancy",
-                          1.0 - self.pool.free_blocks / self.pool.num_blocks)
+            stats["decode_steps"] += chunk
+            stats["emitted_tokens"] += int(emitted.sum())
+            with rec.span("serve.emit"):
+                reqs = [r for _, r in sched.occupied()]
+                for i in sched.record_decode(tokens, emitted, eos):
+                    finish(i)
+                out = self._deltas(reqs)
+            yield from self._emit(out)
 
     def _export_stats(self, stats: dict, elapsed_s: float) -> None:
-        """Mirror the drain's stats dict into the recorder (``serve_``
-        prefix) plus the realized tokens/sec for the whole drain."""
+        """Add the drain's stats into the recorder (``serve_`` prefix):
+        counts as counters, so they sum over drains, and ``peak_*`` as
+        gauges; plus the realized tokens/sec for the whole drain."""
         rec = self.recorder
         if not rec.enabled:
             return
         for k, v in stats.items():
-            rec.gauge_set(f"serve_{k}", v)
+            if k.startswith("peak_"):
+                rec.gauge_set(f"serve_{k}", v)
+            else:
+                rec.counter_add(f"serve_{k}", v)
         tps = stats.get("emitted_tokens", 0) / max(elapsed_s, 1e-9)
         rec.gauge_set("serve_tokens_per_sec", tps)
         rec.event("drain_done", elapsed_s=elapsed_s, tokens_per_sec=tps,
@@ -459,7 +471,8 @@ class ServeEngine:
         live = np.zeros((B,), bool)
         remaining = np.zeros((B,), np.int32)
         stats = {"prefills": 0, "decode_dispatches": 0, "decode_steps": 0,
-                 "emitted_tokens": 0, "preemptions": 0, "peak_concurrency": 0,
+                 "live_slot_steps": 0, "emitted_tokens": 0, "preemptions": 0,
+                 "peak_concurrency": 0,
                  "prefix_hits": 0, "cow_forks": 0, "prefill_tokens": 0,
                  "prefill_s": 0.0, "peak_blocks_in_use": 0,
                  "peak_shared_blocks": 0}
@@ -491,9 +504,7 @@ class ServeEngine:
             live[i] = False
             remaining[i] = 0
             stats["preemptions"] += 1
-            self.recorder.counter_add("serve_preemptions")
-            self.recorder.instant("preempt", rid=req.rid,
-                                  regenerated=len(req.output))
+            self.recorder.instant("serve.preempt", rid=req.rid)
 
         t0 = time.perf_counter()
         try:
@@ -524,19 +535,87 @@ class ServeEngine:
 
     def _paged_loop(self, tok, idx, live, remaining, stats, finish, preempt):
         sched, pool, eos = self.scheduler, self.pool, self.eos_id
-        prefix, chunk = self.prefix, self.decode_chunk
+        prefix, chunk, rec = self.prefix, self.decode_chunk, self.recorder
         # positions one dispatch can advance a slot: decode_chunk serially,
         # or rounds × (k + 1) verify rows under speculation. Emitted rows
         # only ever read positions below idx + adv, so this is also the
         # pre-chunk ensure horizon; window writes past it trash-route.
         adv = chunk if self.spec is None else (
             self._spec_rounds * (self.spec.k + 1))
+
+        def admit_one(i: int, req: Request) -> None:
+            """Prefill (or prefix-hit) one admitted request into slot ``i``
+            and write its pages; it may finish at once, or go back to the
+            queue front."""
+            if not req.admit_s:
+                req.admit_s = time.perf_counter()
+            # re-match at attach time: an earlier admission this round may
+            # have reused a freed-but-cached block the can_admit match
+            # counted on (its generation bump invalidates it)
+            m = prefix.match(req.prompt) if prefix is not None else None
+            if m is not None and m.exact:
+                # write-once fast path: every page (incl. the partial tail)
+                # and the greedy first token are cached — skip prefill
+                # compute entirely and alias the blocks below
+                first, req_cache = m.first_tok, None
+                stats["prefix_hits"] += 1
+                if not req.first_token_s:
+                    req.first_token_s = time.perf_counter()
+                rec.instant("serve.prefix_hit", rid=req.rid,
+                            cached_tokens=m.n_tokens)
+            else:
+                t_pf = time.perf_counter()
+                first, req_cache = self._prefill_first_token(req)
+                stats["prefills"] += 1
+                stats["prefill_s"] += time.perf_counter() - t_pf
+                stats["prefill_tokens"] += len(req.prompt)
+            stats["emitted_tokens"] += 1
+            if req.add_token(first, eos):
+                finish(i)   # prefill token was EOS or budget == 1
+                return
+            if m is not None:
+                pool.attach(i, m.blocks)
+            if not pool.ensure(i, len(req.prompt)):
+                # the can_admit claim was computed against a larger match
+                # than survived to attach time (same-round block reuse) —
+                # hand the request back to the queue front instead of
+                # oversubscribing; it re-admits next round
+                preempt(i)
+                return
+            if m is None or not m.exact:
+                # write once: matched pages stay untouched (their bits are
+                # already this prompt's prefix K/V, and the writer may share
+                # them with live readers) — route them to trash and scatter
+                # only the unmatched tail pages
+                tbl = pool.tables[i].copy()
+                if m is not None:
+                    tbl[:len(m.blocks)] = pool.trash
+                pool.data = self._write_prefill(
+                    pool.data, req_cache, jnp.asarray(tbl))
+                if prefix is not None:
+                    prefix.record(req.prompt,
+                                  pool.tables[i, :pool.owned(i)], first)
+            tok[i], idx[i] = first, len(req.prompt)
+            live[i], remaining[i] = True, req.remaining
+            if self.spec is not None:
+                # draft prefill runs even on exact prefix hits — the draft's
+                # dense cache has no prefix index to alias from, and a
+                # preemption restart rebuilds it the same way
+                with rec.span("serve.draft_prefill", rid=req.rid,
+                              prompt_len=len(req.prompt)):
+                    _, d_cache = self._draft_prefill(
+                        self.spec.draft_params,
+                        self._admission_batch(req, cfg=self.spec.draft_cfg))
+                    self._dcache = self._write_draft(
+                        self._dcache, d_cache, jnp.asarray(i, jnp.int32))
+                stats["draft_prefills"] += 1
+
         while sched.has_work():
             # admission gated on free blocks, not free slots: a request is
             # admitted iff its prompt (+1 headroom) fits the pool right now,
             # where already-resident shared prefix blocks count as zero
             # additional need. ``claimed`` front-runs the attach/ensure calls
-            # below so one round admitting several requests cannot
+            # in admit_one so one round admitting several requests cannot
             # oversubscribe the free list (can_admit only mutates it when it
             # returns True, i.e. exactly when the head IS admitted).
             claimed = [0]
@@ -547,76 +626,18 @@ class ServeEngine:
                     # deterministic given the workload: admission is pure
                     # host-side scheduling, so this counter is identical
                     # whether obs is on or off
-                    self.recorder.counter_add("serve_admission_rejects")
+                    rec.counter_add("serve_admission_rejects")
                     return False
                 claimed[0] += need
                 return True
 
-            for i, req in sched.admit(can_admit):
-                # re-match at attach time: an earlier admission this round
-                # may have reused a freed-but-cached block the can_admit
-                # match counted on (its generation bump invalidates it)
-                m = prefix.match(req.prompt) if prefix is not None else None
-                if m is not None and m.exact:
-                    # write-once fast path: every page (incl. the partial
-                    # tail) and the greedy first token are cached — skip
-                    # prefill compute entirely and alias the blocks below
-                    first, req_cache = m.first_tok, None
-                    stats["prefix_hits"] += 1
-                    if not req.first_token_s:
-                        req.first_token_s = time.perf_counter()
-                    self.recorder.counter_add("serve_prefix_hits")
-                    self.recorder.instant("prefix_hit", rid=req.rid,
-                                          cached_tokens=m.n_tokens)
-                else:
-                    t_pf = time.perf_counter()
-                    first, req_cache = self._prefill_first_token(req)
-                    stats["prefills"] += 1
-                    stats["prefill_s"] += time.perf_counter() - t_pf
-                    stats["prefill_tokens"] += len(req.prompt)
-                stats["emitted_tokens"] += 1
-                if req.add_token(first, eos):
-                    finish(i)   # prefill token was EOS or budget == 1
-                    yield from self._emit([req])
-                    continue
-                if m is not None:
-                    pool.attach(i, m.blocks)
-                if not pool.ensure(i, len(req.prompt)):
-                    # the can_admit claim was computed against a larger
-                    # match than survived to attach time (same-round block
-                    # reuse) — hand the request back to the queue front
-                    # instead of oversubscribing; it re-admits next round
-                    preempt(i)
-                    continue
-                if m is None or not m.exact:
-                    # write once: matched pages stay untouched (their bits
-                    # are already this prompt's prefix K/V, and the writer
-                    # may share them with live readers) — route them to
-                    # trash and scatter only the unmatched tail pages
-                    tbl = pool.tables[i].copy()
-                    if m is not None:
-                        tbl[:len(m.blocks)] = pool.trash
-                    pool.data = self._write_prefill(
-                        pool.data, req_cache, jnp.asarray(tbl))
-                    if prefix is not None:
-                        prefix.record(req.prompt,
-                                      pool.tables[i, :pool.owned(i)], first)
-                tok[i], idx[i] = first, len(req.prompt)
-                live[i], remaining[i] = True, req.remaining
-                if self.spec is not None:
-                    # draft prefill runs even on exact prefix hits — the
-                    # draft's dense cache has no prefix index to alias from,
-                    # and a preemption restart rebuilds it the same way
-                    with self.recorder.span("draft_prefill", rid=req.rid,
-                                            prompt_len=len(req.prompt)):
-                        _, d_cache = self._draft_prefill(
-                            self.spec.draft_params,
-                            self._admission_batch(req,
-                                                  cfg=self.spec.draft_cfg))
-                        self._dcache = self._write_draft(
-                            self._dcache, d_cache, jnp.asarray(i, jnp.int32))
-                    stats["draft_prefills"] += 1
-                yield from self._emit([req])
+            with rec.span("serve.admit"):
+                admitted = sched.admit(can_admit)
+            for i, req in admitted:
+                with rec.span("serve.admit", rid=req.rid):
+                    admit_one(i, req)
+                    out = self._deltas([req])
+                yield from self._emit(out)
             stats["peak_concurrency"] = max(stats["peak_concurrency"],
                                             len(sched.occupied()))
             stats["peak_blocks_in_use"] = max(
@@ -631,37 +652,36 @@ class ServeEngine:
             # before the device program launches. If the pool runs dry,
             # evict the youngest request — it has the least work to redo
             # and re-queues at the front, keeping FIFO.
-            for i, req in sorted(sched.occupied(),
-                                 key=lambda t: t[1].admit_seq):
-                if not live[i]:
-                    continue
-                page = int(idx[i]) // self.block_size
-                while pool.needs_fork(i, page):
-                    if pool.free_blocks:
-                        old, new = pool.fork_for_write(i, page)
-                        pool.data = self._copy_block(
-                            pool.data, jnp.asarray(old, jnp.int32),
-                            jnp.asarray(new, jnp.int32))
-                        stats["cow_forks"] += 1
-                        self.recorder.counter_add("serve_cow_forks")
-                        break
-                    preempt(sched.youngest())   # may drop the shared ref
-                if not live[i]:
-                    continue   # preempted itself while hunting fork room
-                need = int(idx[i]) + min(adv, int(remaining[i]))
-                while not pool.ensure(i, need):
-                    victim = sched.youngest()
-                    if victim == i and len(sched.occupied()) == 1:
-                        # unreachable: submit() caps a lone request's total
-                        # need at the pool size
-                        raise RuntimeError("block pool exhausted by a "
-                                           "single request")
-                    preempt(victim)
-                    if victim == i:
-                        break
-            stats["peak_shared_blocks"] = max(
-                stats["peak_shared_blocks"], int((pool._refs > 1).sum()))
-            self._boundary_gauges(stats)
+            with rec.span("serve.block_budget"):
+                for i, req in sorted(sched.occupied(),
+                                     key=lambda t: t[1].admit_seq):
+                    if not live[i]:
+                        continue
+                    page = int(idx[i]) // self.block_size
+                    while pool.needs_fork(i, page):
+                        if pool.free_blocks:
+                            old, new = pool.fork_for_write(i, page)
+                            pool.data = self._copy_block(
+                                pool.data, jnp.asarray(old, jnp.int32),
+                                jnp.asarray(new, jnp.int32))
+                            stats["cow_forks"] += 1
+                            break
+                        preempt(sched.youngest())  # may drop the shared ref
+                    if not live[i]:
+                        continue   # preempted itself while hunting fork room
+                    need = int(idx[i]) + min(adv, int(remaining[i]))
+                    while not pool.ensure(i, need):
+                        victim = sched.youngest()
+                        if victim == i and len(sched.occupied()) == 1:
+                            # unreachable: submit() caps a lone request's
+                            # total need at the pool size
+                            raise RuntimeError("block pool exhausted by a "
+                                               "single request")
+                        preempt(victim)
+                        if victim == i:
+                            break
+                stats["peak_shared_blocks"] = max(
+                    stats["peak_shared_blocks"], int((pool._refs > 1).sum()))
             if not live.any():
                 continue
             # length-clamp: hand the device only the first `hw` table columns
@@ -671,14 +691,13 @@ class ServeEngine:
             # re-specializes O(log max_blocks) times, not once per width.
             hw = min(1 << max(pool.high_water() - 1, 0).bit_length(),
                      pool.max_blocks)
-            with self.recorder.span("decode_chunk", steps=adv):
+            with rec.span("serve.decode_dispatch", steps=adv):
+                stats["live_slot_steps"] += int(live.sum()) * adv
                 if self.spec is None:
                     out = self._paged_decode(
                         self.params, jnp.asarray(tok), pool.data,
                         jnp.asarray(pool.tables[:, :hw]), jnp.asarray(idx),
                         jnp.asarray(live), jnp.asarray(remaining))
-                    (tok_d, pool.data, idx_d, live_d, remaining_d, tokens,
-                     emitted) = out
                 else:
                     out = self._spec_decode(
                         self.params, self.spec.draft_params,
@@ -686,30 +705,36 @@ class ServeEngine:
                         jnp.asarray(pool.tables[:, :hw]), jnp.asarray(idx),
                         jnp.asarray(live), jnp.asarray(remaining),
                         self._dcache)
+            with rec.span("serve.decode_wait"):
+                if self.spec is None:
+                    (tok_d, pool.data, idx_d, live_d, remaining_d, tokens,
+                     emitted) = out
+                else:
                     (tok_d, pool.data, idx_d, live_d, remaining_d, tokens,
                      emitted, self._dcache, proposed, accepted) = out
-                    n_p = int(np.asarray(proposed).sum())
-                    n_a = int(np.asarray(accepted).sum())
-                    stats["spec_proposed"] += n_p
-                    stats["spec_accepted"] += n_a
-                    self.recorder.counter_add("serve_spec_proposed", n_p)
-                    self.recorder.counter_add("serve_spec_accepted", n_a)
-            # in place: finish()/preempt() close over these same arrays
-            tok[:], idx[:] = np.asarray(tok_d), np.asarray(idx_d)
-            live[:], remaining[:] = np.asarray(live_d), np.asarray(remaining_d)
+                    stats["spec_proposed"] += int(np.asarray(proposed).sum())
+                    stats["spec_accepted"] += int(np.asarray(accepted).sum())
+                # in place: finish()/preempt() close over these same arrays
+                tok[:], idx[:] = np.asarray(tok_d), np.asarray(idx_d)
+                live[:] = np.asarray(live_d)
+                remaining[:] = np.asarray(remaining_d)
+                tokens, emitted = np.asarray(tokens), np.asarray(emitted)
             stats["decode_dispatches"] += 1
             stats["decode_steps"] += adv
-            stats["emitted_tokens"] += int(np.asarray(emitted).sum())
-            reqs = [r for _, r in sched.occupied()]
-            for i in sched.record_decode(tokens, emitted, eos):
-                finish(i)
-            if self.spec is not None:
-                # speculative rewind: return the worst-case ensure headroom
-                # the verify didn't fill (rejected-window tail blocks) so a
-                # partial acceptance never strands pool pages across chunks
-                for i, _ in sched.occupied():
-                    pool.trim(i, int(idx[i]))
-            yield from self._emit(reqs)
+            stats["emitted_tokens"] += int(emitted.sum())
+            with rec.span("serve.emit"):
+                reqs = [r for _, r in sched.occupied()]
+                for i in sched.record_decode(tokens, emitted, eos):
+                    finish(i)
+                if self.spec is not None:
+                    # speculative rewind: return the worst-case ensure
+                    # headroom the verify didn't fill (rejected-window tail
+                    # blocks) so a partial acceptance never strands pool
+                    # pages across chunks
+                    for i, _ in sched.occupied():
+                        pool.trim(i, int(idx[i]))
+                out = self._deltas(reqs)
+            yield from self._emit(out)
 
     # -- cohort drain (legacy baseline) --------------------------------------
 
@@ -730,6 +755,9 @@ class ServeEngine:
             reqs = [sched.queue.popleft()
                     for _ in range(min(self.max_batch, len(sched.queue)))]
             sched.n_admitted += len(reqs)  # cohorts bypass the slot table
+            now = time.perf_counter()
+            for r in reqs:
+                r.admit_s = now
             stats["peak_concurrency"] = max(stats["peak_concurrency"],
                                             len(reqs))
             batch = self._prefill_inputs(self._pad_batch(reqs))

@@ -46,8 +46,11 @@ class Request:
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
     admit_seq: int = -1               # admission order (preemption picks max)
-    # wall-clock marks filled in by the engine (benchmark latency accounting)
+    # wall-clock marks filled in by the engine (benchmark latency accounting);
+    # admit_s: when the request's first admission began (kept across
+    # preemptions, so admit_s - submit_s is its queue wait)
     submit_s: float = 0.0
+    admit_s: float = 0.0
     first_token_s: float = 0.0
     finish_s: float = 0.0
 

@@ -21,3 +21,29 @@ def _clear_jax_caches():
     process otherwise accumulates enough XLA CPU JIT state to abort."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture
+def profiled_spans(tmp_path):
+    """``run(fn) -> (fn(), spans)``: ``fn`` under ``jax.profiler``, with the
+    spans of the profiler trace's host plane whose names are in
+    ``repro.obs.SPANS``, as sorted ``(name, start_ns, end_ns)``. TraceMe may
+    write a span's args after a ``#`` in its name."""
+    from repro.obs import SPANS
+
+    def run(fn):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        path, = tmp_path.glob("**/*.xplane.pb")
+        pd = jax.profiler.ProfileData.from_file(str(path))
+        spans = sorted(
+            (ev.name.split("#")[0], ev.start_ns, ev.start_ns + ev.duration_ns)
+            for pl in pd.planes if pl.name.startswith("/host:")
+            for ln in pl.lines for ev in ln.events
+            if ev.name.split("#")[0] in SPANS)
+        return out, spans
+
+    return run

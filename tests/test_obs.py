@@ -7,6 +7,7 @@ run with the NullRecorder default, and still matches per-step dispatch —
 the metric accumulator rides the scan carry without touching the
 algorithm's op stream.
 """
+import hashlib
 import json
 
 import jax
@@ -15,10 +16,10 @@ import numpy as np
 import pytest
 
 from repro.core import HParams, HypergradConfig, logreg_hyperopt, ring
-from repro.core.engine import Engine
+from repro.core.engine import Engine, key_schedule
 from repro.data import (NodeSampler, make_classification, make_device_sampler,
                         shard_to_nodes, train_val_split)
-from repro.obs import (MetricSet, MetricSpec, NullRecorder, Recorder,
+from repro.obs import (SPANS, MetricSet, MetricSpec, NullRecorder, Recorder,
                        SpanTracer, cli_recorder)
 
 K, D, J = 4, 8, 2
@@ -115,6 +116,25 @@ def test_tracer_spans_nest_and_export(tmp_path):
         assert json.load(f)["traceEvents"]
 
 
+def test_tracer_spans_reach_the_profiler(profiled_spans):
+    """A SpanTracer span is also a TraceAnnotation: inside a profiler
+    session it lands on the host plane, nested as in the code, and it is
+    still kept for the Chrome export."""
+    tr = SpanTracer()
+
+    def body():
+        with tr.span("engine.eval", step=3):
+            with tr.span("engine.on_eval", step=3):
+                jnp.ones(3).sum().block_until_ready()
+
+    _, spans = profiled_spans(body)
+    (n0, s0, e0), (n1, s1, e1) = spans
+    assert (n0, n1) == ("engine.eval", "engine.on_eval")
+    assert s0 <= s1 and e1 <= e0
+    assert [e["name"] for e in tr.events if e["ph"] == "X"] == [
+        "engine.on_eval", "engine.eval"]
+
+
 def test_tracer_span_closes_on_exception():
     tr = SpanTracer()
     with pytest.raises(RuntimeError):
@@ -166,9 +186,24 @@ def test_prometheus_name_sanitization():
     assert "serve_tok_s 1" in rec.prometheus_text().replace(".0", "")
 
 
+def test_trace_only_recorder_records_no_metric():
+    rec = Recorder(tracer=SpanTracer(), metrics=False)
+    assert not rec.enabled
+    rec.counter_add("x"), rec.gauge_set("g", 1.0), rec.observe("o", 0.5)
+    rec.event("e", v=1)
+    with rec.span("engine.eval"):
+        pass
+    snap = rec.snapshot()
+    assert not (snap["counters"] or snap["gauges"] or snap["observations"]
+                or rec.events)
+    assert any(e.get("name") == "engine.eval" for e in rec.tracer.events)
+
+
 def test_cli_recorder_off_and_on(tmp_path):
     rec, fin = cli_recorder(None, None)
     assert isinstance(rec, NullRecorder) and fin() == []
+    rec, fin = cli_recorder(None, str(tmp_path / "t0"))
+    assert rec.tracer is not None and not rec.enabled   # trace only
     rec, fin = cli_recorder(str(tmp_path / "m"), str(tmp_path / "t"))
     rec.counter_add("x")
     with rec.span("s"):
@@ -256,3 +291,90 @@ def test_per_step_dispatch_skips_in_scan_metrics(setup):
     assert snap["counters"]["train_steps"] == 4
     assert "train_consensus_x" not in snap["gauges"]
     assert "eval_upper_loss" in snap["gauges"]
+
+
+def _chunk_fingerprint(eng, state, steps):
+    """sha256 of the program the run's fused chunk lowers to. The run must
+    have built exactly one chunk, the one without the in-scan metric
+    accumulator (an accumulator chunk takes two more arguments and fails to
+    lower here). The compiled text carries per-compile ids; the lowered
+    module does not."""
+    (key, (_, chunk)), = [(k, v) for k, v in eng._jit_cache.items()
+                          if isinstance(k, tuple) and k[0] == "chunk"]
+    assert key[3] is False, "the run threaded the metric accumulator"
+    kbs, kns = key_schedule(jax.random.PRNGKey(0), steps)
+    text = chunk.lower(state, kbs, kns).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_fused_run_spans_on_the_profiler_clock(setup, profiled_spans):
+    """A trace-only recorder puts the run loop's spans on the profiler's
+    host plane: one engine.dispatch per chunk, engine.on_eval nested in
+    engine.eval, and no chunk runs inside an eval. It compiles the same
+    chunk as the NullRecorder, and the run is bitwise the same."""
+    prob, cfg, hp, sample, eval_batch = setup
+    steps, every = 7, 3
+    seen = []
+    runs = {}
+    for name, rec in (("off", None),
+                      ("trace", Recorder(tracer=SpanTracer(),
+                                         metrics=False))):
+        eng = Engine(prob, cfg, hp, ring(K), algo="mdbo", mix="ring_rolled",
+                     recorder=rec)
+
+        def go():
+            return eng.run(sample, eval_batch, steps=steps, eval_every=every,
+                           seed=0, return_state=True,
+                           on_eval=lambda t, s: seen.append(t))
+
+        if name == "trace":
+            (res, state), spans = profiled_spans(go)
+        else:
+            res, state = go()
+        runs[name] = (eng, res, state)
+    (e_off, r_off, s_off), (e_tr, r_tr, s_tr) = runs["off"], runs["trace"]
+    _leaves_equal(s_off, s_tr)
+    assert r_off.upper_loss == r_tr.upper_loss
+    # the trace-only recorder threads no metric accumulator: same chunk
+    assert (_chunk_fingerprint(e_off, s_off, every)
+            == _chunk_fingerprint(e_tr, s_tr, every))
+
+    by = {n: [(s, e) for m, s, e in spans if m == n] for n in SPANS}
+    chunks = -(-steps // every)
+    assert len(by["engine.dispatch"]) == chunks
+    assert len(by["engine.eval"]) == chunks + 1        # t=0 too
+    assert len(by["engine.on_eval"]) == chunks + 1
+    assert not by["engine.drain"]                      # metrics are off
+    for s, e in by["engine.on_eval"]:
+        assert any(es <= s and e <= ee for es, ee in by["engine.eval"])
+    for s, e in by["engine.dispatch"]:
+        assert not any(es < e and s < ee for es, ee in by["engine.eval"])
+    # in memory, the same spans for the Chrome export
+    names = [e["name"] for e in e_tr.recorder.tracer.events
+             if e["ph"] == "X"]
+    assert names.count("engine.dispatch") == chunks
+    assert set(names) <= set(SPANS)
+
+
+def test_metrics_recorder_drains_under_its_span(setup):
+    """With metrics on, the in-scan drain runs once per chunk under
+    engine.drain."""
+    prob, cfg, hp, sample, eval_batch = setup
+    rec = Recorder(tracer=SpanTracer())
+    eng = Engine(prob, cfg, hp, ring(K), algo="mdbo", mix="ring_rolled",
+                 recorder=rec)
+    eng.run(sample, eval_batch, steps=6, eval_every=3, seed=0)
+    names = [e["name"] for e in rec.tracer.events if e["ph"] == "X"]
+    assert names.count("engine.drain") == 2
+    assert sum(1 for e in rec.events if e["kind"] == "drain") == 2
+
+
+def test_per_step_dispatch_spans(setup):
+    prob, cfg, hp, sample, eval_batch = setup
+    rec = Recorder(tracer=SpanTracer(), metrics=False)
+    eng = Engine(prob, cfg, hp, ring(K), algo="mdbo", mix="ring_rolled",
+                 dispatch="per_step", recorder=rec)
+    eng.run(sample, eval_batch, steps=4, eval_every=2, seed=0)
+    names = [e["name"] for e in rec.tracer.events if e["ph"] == "X"]
+    assert names.count("engine.dispatch") == 4
+    assert names.count("engine.eval") == 3

@@ -1,5 +1,6 @@
 """ServeEngine observability: counters/latency stats against a forced-
-preemption paged trace, and the bitwise stream contract with obs enabled.
+preemption paged trace, the loop's spans on the profiler's clock, and the
+bitwise stream contract with obs enabled.
 
 Serve obs is host-side only (counters, spans, and timestamps taken at chunk
 boundaries the scheduler already crosses), so a live Recorder must not
@@ -13,7 +14,7 @@ import pytest
 
 from repro.configs import get
 from repro.models import init_params
-from repro.obs import Recorder, SpanTracer
+from repro.obs import INSTANTS, SPANS, Recorder, SpanTracer
 from repro.serve import ServeEngine
 
 PROMPT_BUDGETS = [9, 8, 10, 7, 9]
@@ -58,7 +59,7 @@ def test_preemption_and_admission_counters(model):
     assert snap["counters"]["serve_finished"] == len(rids)
     # every preemption leaves an instant marker carrying the victim rid
     marks = [e for e in rec.tracer.to_chrome_trace()["traceEvents"]
-             if e.get("ph") == "i" and e["name"] == "preempt"]
+             if e.get("ph") == "i" and e["name"] == "serve.preempt"]
     assert len(marks) == eng.stats["preemptions"]
     assert all(m["args"]["rid"] in rids for m in marks)
 
@@ -119,7 +120,65 @@ def test_boundary_gauges_and_drain_stats(model):
     rec = Recorder()
     eng = _paged(model, recorder=rec)
     _drain(eng, _workload(model[0]))
-    g = rec.snapshot()["gauges"]
-    assert "serve_block_occupancy" in g and 0.0 <= g["serve_block_occupancy"] <= 1.0
+    snap = rec.snapshot()
+    g, c = snap["gauges"], snap["counters"]
+    # peaks are gauges, counts are counters: one type per name
+    assert 0 < g["serve_peak_blocks_in_use"] <= eng.pool.num_blocks
+    assert g["serve_peak_concurrency"] == eng.stats["peak_concurrency"]
     assert g["serve_tokens_per_sec"] > 0
-    assert g["serve_preemptions"] == eng.stats["preemptions"]
+    assert c["serve_preemptions"] == eng.stats["preemptions"]
+    assert c["serve_live_slot_steps"] == eng.stats["live_slot_steps"]
+    assert not set(g) & set(c)
+    samples = [ln.split()[0] for ln in rec.prometheus_text().splitlines()
+               if not ln.startswith("#")]
+    assert len(samples) == len(set(samples))        # no name sampled twice
+
+
+def test_paged_spans_stamps_and_occupancy(model, profiled_spans):
+    """A trace-only recorder puts the paged loop's phases on the profiler's
+    host plane: each prefill carries its rid and sits inside its request's
+    serve.admit, and no phase overlaps another. Every request's admission
+    stamp lies between its submission and its first token, and the slot
+    steps dispatched never exceed the decode steps times the slots. The
+    streams are the recorder-off streams."""
+    workload = _workload(model[0])
+    _, off = _drain(_paged(model), workload)
+    rec = Recorder(tracer=SpanTracer(), metrics=False)
+    eng = _paged(model, recorder=rec)
+    (rids, on), spans = profiled_spans(lambda: _drain(eng, workload))
+    assert on == off
+    assert rec.snapshot()["counters"] == {}          # traces only
+
+    mem = [e for e in rec.tracer.events if e["ph"] in ("X", "i")]
+    assert {e["name"] for e in mem if e["ph"] == "X"} <= set(SPANS)
+    assert {e["name"] for e in mem if e["ph"] == "i"} <= set(INSTANTS)
+    prefills = [e for e in mem if e["name"] == "serve.prefill"]
+    assert len(prefills) == eng.stats["prefills"]
+    assert {e["args"]["rid"] for e in prefills} == set(rids)
+
+    by = {n: [(s, e) for m, s, e in spans if m == n] for n in SPANS}
+    assert len(by["serve.prefill"]) == eng.stats["prefills"]
+    assert len(by["serve.decode_dispatch"]) == eng.stats["decode_dispatches"]
+    for s, e in by["serve.prefill"]:
+        assert any(a <= s and e <= b for a, b in by["serve.admit"])
+    top = sorted(iv for n in ("serve.block_budget", "serve.decode_dispatch",
+                              "serve.decode_wait", "serve.emit")
+                 for iv in by[n])
+    assert all(e <= s for (_, e), (s, _) in zip(top, top[1:]))
+
+    for rid in rids:
+        r = eng.completed[rid]
+        assert r.submit_s <= r.admit_s <= r.first_token_s <= r.finish_s
+    st = eng.stats
+    assert 0 < st["live_slot_steps"] <= st["decode_steps"] * eng.max_batch
+
+
+def test_queue_wait_observed(model):
+    rec = Recorder()
+    eng = _paged(model, recorder=rec)
+    rids, _ = _drain(eng, _workload(model[0]))
+    q = rec.snapshot()["observations"]["serve_queue_s"]
+    assert q["count"] == len(rids)
+    waits = [eng.completed[r].admit_s - eng.completed[r].submit_s
+             for r in rids]
+    assert q["max"] == pytest.approx(max(waits)) and min(waits) >= 0.0
