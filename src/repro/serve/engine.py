@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import grid_steps
 from repro.models.config import ModelConfig
 from repro.serve.batch import (BlockPool, PrefixIndex, copy_block,
                                init_slot_cache, slot_axes, write_prefill,
@@ -476,6 +477,9 @@ class ServeEngine:
                  "prefix_hits": 0, "cow_forks": 0, "prefill_tokens": 0,
                  "prefill_s": 0.0, "peak_blocks_in_use": 0,
                  "peak_shared_blocks": 0}
+        if self._counts_grid:
+            stats.update(paged_grid_steps=0, paged_live_grid_steps=0,
+                         paged_live_pages=0)
         if self.spec is not None:
             stats.update(spec_proposed=0, spec_accepted=0, draft_prefills=0)
             src = None
@@ -532,6 +536,25 @@ class ServeEngine:
         if m.exact and len(req.prompt) % self.block_size:
             need += 1
         return need
+
+    @property
+    def _counts_grid(self) -> bool:
+        """Whether decode chunks run the block-native paged kernel step."""
+        return self.spec is None and self.kv_impl in ("kernel", "pallas")
+
+    def _count_grid(self, stats, idx, emitted, hw: int) -> None:
+        """Add the paged kernel's grid over the chunk just run: its step
+        ``s`` called the kernel once per layer, on a table ``hw`` pages
+        wide, with lengths ``idx + 1 + s`` on the slots it emitted for and
+        0 on the rest (``kernels.paged_attention.grid_steps``)."""
+        step = np.arange(len(emitted))[:, None]
+        lengths = np.where(emitted, idx[None] + 1 + step, 0)
+        n_layers = self.pool.data["kv"]["k"].shape[2]
+        steps, live_steps, live_pages = grid_steps(lengths, hw,
+                                                   self.block_size)
+        stats["paged_grid_steps"] += steps * n_layers
+        stats["paged_live_grid_steps"] += live_steps * n_layers
+        stats["paged_live_pages"] += live_pages * n_layers
 
     def _paged_loop(self, tok, idx, live, remaining, stats, finish, preempt):
         sched, pool, eos = self.scheduler, self.pool, self.eos_id
@@ -714,11 +737,13 @@ class ServeEngine:
                      emitted, self._dcache, proposed, accepted) = out
                     stats["spec_proposed"] += int(np.asarray(proposed).sum())
                     stats["spec_accepted"] += int(np.asarray(accepted).sum())
+                tokens, emitted = np.asarray(tokens), np.asarray(emitted)
+                if self._counts_grid:
+                    self._count_grid(stats, idx, emitted, hw)
                 # in place: finish()/preempt() close over these same arrays
                 tok[:], idx[:] = np.asarray(tok_d), np.asarray(idx_d)
                 live[:] = np.asarray(live_d)
                 remaining[:] = np.asarray(remaining_d)
-                tokens, emitted = np.asarray(tokens), np.asarray(emitted)
             stats["decode_dispatches"] += 1
             stats["decode_steps"] += adv
             stats["emitted_tokens"] += int(emitted.sum())

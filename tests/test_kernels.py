@@ -176,6 +176,36 @@ def test_paged_dead_slot_emits_zeros():
     out = paged_attention_pallas(q, kp, vp, tables, lens, 0, interpret=True)
     assert float(jnp.abs(out[:2]).max()) == 0.0
     assert float(jnp.abs(out[2]).max()) > 0.0
+    # a call with no live slot at all
+    out = paged_attention_pallas(q, kp, vp, tables, lens * 0, 0,
+                                 interpret=True)
+    assert float(jnp.abs(out).max()) == 0.0
+
+
+@pytest.mark.parametrize("n_pages", [1, 2, 4, 8, 16, 64])
+def test_paged_parity_pages_per_step(n_pages):
+    """A grid step walks P = min(8, n_pages) pages: P clamped below 8 and
+    full, lengths one short of, at and one past a group (P * bs), a full
+    table, dead slots, and table entries past each length aimed at a
+    poisoned trash block, all match the oracle; dead slots emit zeros."""
+    import numpy as np
+    from repro.kernels.paged_attention import pages_per_step
+    bs = 8
+    group = pages_per_step(n_pages) * bs
+    lengths = [n for n in (group - 1, group, group + 1, n_pages * bs)
+               if n <= n_pages * bs] + [0, 0]
+    B = len(lengths)
+    num_blocks = sum(-(-n // bs) for n in lengths) + 1
+    q, kp, vp, tables, lens = _paged_case(
+        jax.random.PRNGKey(n_pages), B, 4, 2, 16, 2, bs, n_pages, num_blocks,
+        lengths, rng_seed=n_pages)
+    kp = kp.at[num_blocks].set(1e4)
+    vp = vp.at[num_blocks].set(-1e4)
+    out = paged_attention_pallas(q, kp, vp, tables, lens, 1, interpret=True)
+    ref = paged_attention_ref(q, kp, vp, tables, lens, 1)
+    assert (np.asarray(tables)[np.asarray(lens) == 0] == num_blocks).all()
+    assert float(jnp.max(jnp.abs(out - ref))) < TOL[jnp.float32]
+    assert float(jnp.abs(out[-2:]).max()) == 0.0
 
 
 @settings(max_examples=12, deadline=None)

@@ -310,3 +310,92 @@ def test_multi_kernel_ignores_garbage_past_row_lengths():
         np.asarray(multi_kernel(q, kp2, vp2, tables, lengths,
                                 interpret=True)),
         np.asarray(clean_ker))
+
+
+# -- several pages per grid step ----------------------------------------------
+
+
+@pytest.mark.parametrize("Q", [1, 4])
+@pytest.mark.parametrize("n_pages", [1, 2, 4, 8, 16, 64])
+def test_multi_kernel_parity_pages_per_step(n_pages, Q):
+    """The verify window through a grid of P = min(8, n_pages) pages a step:
+    lengths one short of, at and one past a group (P * bs), a full table, a
+    dead slot, and table entries past each length aimed at a poisoned trash
+    block match the oracle; the dead slot emits zeros."""
+    from repro.kernels import paged_attention_multi_ref
+    from repro.kernels.paged_attention import (
+        pages_per_step, paged_attention_multi as multi_kernel)
+
+    rng = np.random.default_rng(60 + n_pages + Q)
+    bs, Hkv, G, Dh, L = 8, 2, 2, 8, 2
+    group = pages_per_step(n_pages) * bs
+    lengths = [n for n in (group - 1, group, group + 1, n_pages * bs)
+               if n <= n_pages * bs] + [0]
+    B = len(lengths)
+    used = [-(-n // bs) for n in lengths]
+    trash = sum(used)
+    kp = np.asarray(rng.normal(size=(trash + 1, bs, L, Hkv, Dh)), np.float32)
+    vp = np.asarray(rng.normal(size=(trash + 1, bs, L, Hkv, Dh)), np.float32)
+    kp[trash], vp[trash] = 1e4, -1e4
+    order = rng.permutation(trash)
+    tables = np.full((B, n_pages), trash, np.int32)
+    for i, n in enumerate(used):
+        tables[i, :n] = order[sum(used[:i]):sum(used[:i]) + n]
+    q = jnp.asarray(rng.normal(size=(B, Q, Hkv * G, Dh)), jnp.float32)
+    args = (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32))
+    want = paged_attention_multi_ref(*args, layer=1)
+    got = multi_kernel(*args, layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    assert (np.asarray(got[-1]) == 0).all()
+
+
+@pytest.mark.parametrize("n_pages", [1, 3, 8, 16, 64])
+def test_grid_steps_matches_a_walk_of_the_grid(n_pages):
+    """The host count of the kernel's grid equals a walk of its work list:
+    each slot's page groups that start below its length, one step for a
+    call with none, and the pages below the length those steps carry."""
+    from repro.kernels.paged_attention import grid_steps, pages_per_step
+
+    bs = 4
+    P = pages_per_step(n_pages)
+    rng = np.random.default_rng(n_pages)
+    lengths = rng.integers(0, n_pages * bs + 1, size=(3, 5))
+    lengths[0, 0] = 0
+    lengths[1] = 0                     # a call with no live slot
+    steps = live_steps = live_pages = 0
+    for call in lengths:
+        work = [(n, j) for n in call for j in range(-(-n_pages // P))
+                if j * P * bs < n]
+        steps += max(len(work), 1)
+        live_steps += len(work)
+        live_pages += sum((j * P + p) * bs < n for n, j in work
+                          for p in range(P))
+    assert grid_steps(lengths, n_pages, bs) == (steps, live_steps,
+                                                live_pages)
+
+
+def test_engine_counts_the_paged_grid(model):
+    """The decode loop's grid counters: with no EOS and no preemption, a
+    request of prompt p and budget n runs n - 1 decode steps at lengths
+    p + 1 .. p + n - 1, once per layer."""
+    cfg, params = model
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(0, cfg.vocab, size=int(n)), int(b))
+            for n, b in ((3, 9), (13, 4), (6, 12))]
+    eng, _ = _drain(cfg, params, reqs, "kernel", max_batch=2,
+                    decode_chunk=3, block_size=4)
+    st, bs, L = eng.stats, eng.block_size, cfg.n_layers
+    assert st["preemptions"] == 0
+    pages = sum(-(-t // bs) for p, n in reqs
+                for t in range(len(p) + 1, len(p) + n))
+    assert st["paged_live_pages"] == pages * L
+    assert 0 < st["paged_live_grid_steps"] <= st["paged_live_pages"]
+    # a kernel call with no live slot (its requests done mid-chunk) takes
+    # one grid step
+    assert (st["paged_live_grid_steps"] <= st["paged_grid_steps"]
+            <= st["paged_live_grid_steps"] + st["decode_steps"] * L)
+    ref, _ = _drain(cfg, params, reqs, "reference", max_batch=2,
+                    decode_chunk=3, block_size=4)
+    assert "paged_grid_steps" not in ref.stats

@@ -46,16 +46,17 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _operands(sharding, shape, q_len):
+def _operands(sharding, shape, q_len, b=B, n_pages=N_PAGES,
+              num_blocks=NUM_BLOCKS):
     H, Hkv, Dh, L = shape
 
     def sds(s, dt):
         return jax.ShapeDtypeStruct(s, dt, sharding=sharding)
 
-    q_shape = (B, H, Dh) if q_len is None else (B, q_len, H, Dh)
-    pool = sds((NUM_BLOCKS + 1, BLOCK_SIZE, L, Hkv, Dh), jnp.bfloat16)
+    q_shape = (b, H, Dh) if q_len is None else (b, q_len, H, Dh)
+    pool = sds((num_blocks + 1, BLOCK_SIZE, L, Hkv, Dh), jnp.bfloat16)
     return (sds(q_shape, jnp.bfloat16), pool, pool,
-            sds((B, N_PAGES), jnp.int32), sds((B,), jnp.int32),
+            sds((b, n_pages), jnp.int32), sds((b,), jnp.int32),
             sds((), jnp.int32))
 
 
@@ -72,3 +73,16 @@ def test_paged_attention_multi_compiles_for_v5e(one_chip, shape, q_len):
     args = _operands(one_chip, SHAPES[shape], q_len=q_len)
     compiled = paged_attention_multi.lower(*args, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_compiles_at_serve_chat_shape(one_chip):
+    """The serving benchmark's decode call: 32 slots, a 128-page table and a
+    4,608-block pool at smollm-360m widths. The kernel's custom call keeps
+    ``paged_attention`` in its op name, which the benchmark's trace
+    reduction matches to time the kernel."""
+    args = _operands(one_chip, SHAPES["smollm-360m"], q_len=None, b=32,
+                     n_pages=128, num_blocks=4608)
+    text = paged_attention.lower(*args, interpret=False).compile().as_text()
+    calls = [ln.split("=")[0].strip() for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls and all("paged_attention" in c for c in calls), calls
