@@ -37,7 +37,7 @@ from bench import run as bench_run  # noqa: E402
 def _load(workload: str):
     cell = common.load_json(common.BENCH / "workloads" / f"{workload}.json")
     cell.setdefault("name", workload)
-    cfg = common.load_json(common.BENCH / "configs" / f"{cell['config']}.json")
+    cfg = common.load_config(common.BENCH, cell["config"])
     driver = common.load_module(common.BENCH / "drivers"
                                 / f"{cell['driver']}.py")
     return cell, cfg, driver
@@ -94,10 +94,11 @@ def knee(args) -> None:
     import jax
     from repro.serve import ServeEngine
 
-    from bench import traffic, weights
+    from bench import models, traffic
     cell, cfg, driver = _load(args.workload)
-    mcfg = driver.model_config(cfg)
-    params = weights.dense_lm(cfg, 0)
+    model = models.of(cfg)
+    mcfg = model.program_config(cfg)
+    params = model.weights(cfg, 0)
     jax.block_until_ready(params)
     eng = ServeEngine(mcfg, params, **cell["engine"])
     driver._warm(eng, cell, mcfg.vocab)

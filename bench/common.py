@@ -6,12 +6,15 @@ file of its own, found by name:
 
 * ``bench/workloads/<cell>.json``  — the cell: config, driver, traffic, check
 * ``bench/configs/<config>.json``  — the configuration as it is run
+* ``bench/models/<model_type>.py`` — a serving architecture's program
+  config, weights, reference and counts (``bench/models/__init__.py``)
 * ``bench/drivers/<driver>.py``    — ``run(ctx, cell, cfg, seed, seconds)``
   and ``check(cell, cfg, seed, records)``
 * ``bench/metrics/<metric>.py``    — ``KIND``, ``UNIT``, ``read(records, red)``
 """
 from __future__ import annotations
 
+import gc
 import importlib.util
 import json
 import math
@@ -29,11 +32,21 @@ TRACE_DIR = ROOT / ".bench_cache" / "trace"
 ANNOTATIONS = ("bench.submit", "bench.stream_next", "bench.wait",
                "bench.engine_run", "bench.eval")
 TRACED = "bench.traced"   # spans the traced window on the host
+# where a loaded configuration came from, so that its model module is
+# looked up beside it (``bench.models.of``)
+BENCH_DIR_KEY = "_bench_dir"
 
 
 def load_json(path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_config(bench_dir, name: str) -> dict:
+    """``bench_dir/configs/<name>.json``, marked with ``bench_dir``."""
+    cfg = load_json(pathlib.Path(bench_dir) / "configs" / f"{name}.json")
+    cfg[BENCH_DIR_KEY] = str(bench_dir)
+    return cfg
 
 
 def load_module(path, name: str | None = None):
@@ -103,12 +116,29 @@ class Context:
         self.trace_t0 = self.trace_t1 = None
         self.compiles = CompileCounter.get()
         self._traced = None
+        # (generation, seconds) of each garbage collection from the
+        # window's opening until the driver returns
+        self.gc_pauses: list[tuple[int, float]] = []
+        self._gc_t = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t = time.perf_counter()
+        elif self._gc_t is not None:
+            self.gc_pauses.append((info["generation"],
+                                   time.perf_counter() - self._gc_t))
+            self._gc_t = None
 
     def open_window(self) -> float:
         self.compiles.events.clear()
         self.compiles.active = True
+        gc.callbacks.append(self._on_gc)
         self.t_open = time.perf_counter()
         return self.t_open
+
+    def end_gc_watch(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     def close_window(self) -> float:
         """Mark the end of the window; compiles are counted on until the

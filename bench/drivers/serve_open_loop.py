@@ -10,8 +10,9 @@ The window opens when the first request is due and closes when the last one
 is. The engine then drains what was admitted, for at most a minute; a
 request that has not finished by then counts as missing.
 
-``check`` compares what the timed path served with the float32 reference
-(``bench/reference/dense_lm.py``) on a sample drawn from the seed.
+``check`` compares what the timed path served with the plain float32
+reference of the configuration's architecture (``bench/models/<model_type>.py``)
+on a sample drawn from the seed.
 """
 from __future__ import annotations
 
@@ -19,25 +20,14 @@ import time
 
 import numpy as np
 
-from bench import flops, traffic, weights
+from bench import models, traffic
 from bench.common import annotate
-from bench.reference import dense_lm
+from bench.reference import gap_of
 
 DRAIN_S = 60.0
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a bench configuration file."""
-    import jax.numpy as jnp
-    from repro.models.config import ModelConfig
-    d = flops.lm_dims(cfg)
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=d["L"], d_model=d["D"],
-        n_heads=d["H"], n_kv_heads=d["Hkv"], head_dim=d["Dh"], d_ff=d["F"],
-        vocab=d["V"], rope_theta=float(cfg["rope_theta"]),
-        dtype=jnp.dtype(cfg["torch_dtype"]),
-        param_dtype=jnp.dtype(cfg["param_dtype"]),
-        remat=bool(cfg.get("remat", False)))
+# ServeEngine.stats counts summed over the drains of a run
+SUMMED = ("prefills", "prefill_s", "decode_steps", "preemptions",
+          "paged_grid_steps", "paged_live_grid_steps", "paged_live_pages")
 
 
 def warm_prompts(cell: dict) -> list[int]:
@@ -71,8 +61,9 @@ def run(ctx, cell: dict, cfg: dict, seed: int, seconds: float) -> dict:
     import jax
     from repro.serve import ServeEngine
 
-    mcfg = model_config(cfg)
-    params = weights.dense_lm(cfg, seed)
+    model = models.of(cfg)
+    mcfg = model.program_config(cfg)
+    params = model.weights(cfg, seed)
     jax.block_until_ready(params)
     eng = ServeEngine(mcfg, params, **cell["engine"])
     _warm(eng, cell, mcfg.vocab)
@@ -92,8 +83,8 @@ def _drive(ctx, eng, reqs, seconds: float, cell: dict) -> dict:
     t_last = [None] * n
     late = [0.0] * n
     deltas = []          # (host time, request index, first index, count)
-    totals = {"prefills": 0, "prefill_s": 0.0, "decode_steps": 0,
-              "preemptions": 0, "drains": 0, "peak_blocks_in_use": 0}
+    totals = dict.fromkeys(SUMMED, 0)
+    totals.update(prefill_s=0.0, drains=0, peak_blocks_in_use=0)
     trace_from = seconds * float(cell.get("trace_at", 0.4))
     trace_to = trace_from + float(cell.get("trace_s", 3.0))
     nxt = 0
@@ -161,7 +152,7 @@ def _drive(ctx, eng, reqs, seconds: float, cell: dict) -> dict:
             gen.close()
         st = eng.stats
         totals["drains"] += 1
-        for k in ("prefills", "prefill_s", "decode_steps", "preemptions"):
+        for k in SUMMED:
             totals[k] += st.get(k, 0)
         totals["peak_blocks_in_use"] = max(totals["peak_blocks_in_use"],
                                            st.get("peak_blocks_in_use", 0))
@@ -210,18 +201,17 @@ def served_gaps(records: dict, cfg: dict, pick, *, pad_to: int,
     reference's best. With ``control`` the tokens are the ones the fp8
     control puts first at the same positions instead."""
     params, reqs = records["params"], records["requests"]
+    reference = models.of(cfg).reference_logits
     widest = []
     for i in pick:
         r = reqs[i]
-        ref = dense_lm.next_token_logits(params, cfg, r["prompt"],
-                                         r["tokens"], pad_to=pad_to)
+        ref = reference(params, cfg, r["prompt"], r["tokens"], pad_to=pad_to)
         toks = r["tokens"]
         if control:
-            lq = dense_lm.next_token_logits(params, cfg, r["prompt"],
-                                            r["tokens"], pad_to=pad_to,
-                                            quant="fp8")
+            lq = reference(params, cfg, r["prompt"], r["tokens"],
+                           pad_to=pad_to, quant="fp8")
             toks = np.asarray(lq).argmax(axis=-1)
-        widest.append(float(dense_lm.gap_of(ref, toks).max()))
+        widest.append(float(gap_of(ref, toks).max()))
     return widest
 
 

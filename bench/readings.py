@@ -4,10 +4,10 @@ from __future__ import annotations
 
 from bench import common
 
-# names the traced programs carry today (see PERF.md, "Layers"):
-# the jitted decode chunk of the paged engine, and the Pallas kernel
+# the name the jitted decode chunk of the paged engine carries in the trace
+# (see PERF.md, "Layers"); each architecture names its attention kernel in
+# its own module (bench/models/)
 DECODE_MODULE = "jit_chunk"
-PAGED_KERNEL = "paged_attention"
 
 
 def on_chip(records: dict) -> bool:

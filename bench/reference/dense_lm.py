@@ -114,11 +114,3 @@ def next_token_logits(params, cfg: dict, prompt, served, *, pad_to: int,
     rows = len(prompt) - 1 + np.arange(-(-n // 256) * 256)
     rows = np.minimum(rows, pad_to - 1)
     return logits(params, h[jnp.asarray(rows)], quant=quant)[:n]
-
-
-def gap_of(ref_logits, tokens) -> np.ndarray:
-    """How far each chosen token's reference logit lies below the
-    reference's best at that position (0 where they agree)."""
-    ref = np.asarray(ref_logits, np.float64)
-    tokens = np.asarray(tokens)
-    return ref.max(axis=-1) - ref[np.arange(len(tokens)), tokens]

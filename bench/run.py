@@ -89,7 +89,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     bench_dir = pathlib.Path(bench_dir)
     cell = common.load_json(bench_dir / "workloads" / f"{workload}.json")
     cell.setdefault("name", workload)
-    cfg = common.load_json(bench_dir / "configs" / f"{cell['config']}.json")
+    cfg = common.load_config(bench_dir, cell["config"])
     devs = _devices(int(cell["chips"]), require_chip)
     _use_cache(cache_dir)
     driver = common.load_module(bench_dir / "drivers" / f"{cell['driver']}.py")
@@ -98,6 +98,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         trace_dir=trace_dir or common.TRACE_DIR / f"{workload}.{seed}")
     records = driver.run(ctx, cell, cfg, seed, seconds)
     ctx.compiles.active = False
+    ctx.end_gc_watch()
+    records["gc_pauses"] = ctx.gc_pauses
     records.update(setup_s=ctx.setup_s, cfg=cfg, platform=devs[0].platform,
                    device_kind=devs[0].device_kind)
     records["compiles_in_window"] = len(ctx.compiles.events)
@@ -160,6 +162,12 @@ def notes(records: dict) -> list[str]:
              f"setup_s: {records['setup_s']!r}",
              f"window_s: {records['window_s']!r}",
              f"memory: bytes_limit {records['memory_limit']!r}"]
+    gcs = records.get("gc_pauses", [])
+    lines.append("gc_after_open: %d collections, %d of generation 2, "
+                 "%r ms in all, longest %r ms" % (
+                     len(gcs), sum(1 for g, _ in gcs if g == 2),
+                     1e3 * sum(s for _, s in gcs),
+                     1e3 * max((s for _, s in gcs), default=0.0)))
     if records["kind"] == "serve":
         late = [r["late_s"] for r in records["requests"]]
         lines.append(
@@ -167,10 +175,25 @@ def notes(records: dict) -> list[str]:
                 1e3 * common.percentile(late, 50),
                 1e3 * common.percentile(late, 95), 1e3 * max(late),
                 len(late)))
+        lines.append("delivery_gaps_ms: longest five in the window %r" %
+                     [round(1e3 * g, 1) for g in
+                      delivery_gaps(records)[:5]])
         e = records["engine"]
         lines.append("engine: " + ", ".join(f"{k}={v!r}"
                                             for k, v in e.items()))
     return lines
+
+
+def delivery_gaps(records: dict) -> list[float]:
+    """Seconds between consecutive token deliveries in the window while a
+    request was streaming, longest first: a host stall shows as a gap far
+    over one decode chunk."""
+    spans = [(r["first_s"], r["last_s"]) for r in records["requests"]
+             if r["first_s"] is not None and r["last_s"] is not None]
+    ts = sorted(t for t, *_ in records["deltas"] if t <= records["window_s"])
+    return sorted((b - a for a, b in zip(ts, ts[1:])
+                   if any(f <= a and b <= l for f, l in spans)),
+                  reverse=True)
 
 
 def main(argv=None) -> int:

@@ -24,6 +24,12 @@ TINY_SERVE = {
     # the fp8 control 0.35 to 0.78, and an altered token about 4.6
     "check": {"logit_gap": 0.1, "sample_tokens": 96, "pad_to": 128},
 }
+# a second architecture, under keys of its own (bench/tests/fixtures/tiny_alt.py)
+TINY_ALT = {
+    "name": "tiny-alt", "model_type": "tiny_alt", "n_layer": 2,
+    "d_model": 64, "n_head": 4, "n_kv_head": 1, "d_ff": 96, "vocab": 384,
+    "rope_theta": 10000.0,
+}
 TINY_LOGREG_DATA = {"n": 4000, "d": 20}
 TINY_BILEVEL = {
     "config": "tiny-logreg",
@@ -60,6 +66,22 @@ def bench_copy(tmp: pathlib.Path) -> pathlib.Path:
     write("workloads/tiny.bilevel.json",
           _merge(load("workloads/logreg-a9a.mdbo-k8.json"), TINY_BILEVEL))
     return dst
+
+
+def add_model(bench: pathlib.Path, model_type: str, cfg: dict,
+              module: pathlib.Path | None = None) -> str:
+    """Add an architecture as new files only: its module (when given) as
+    ``models/<model_type>.py``, the configuration ``cfg``, and a serve
+    workload on it that is ``tiny.serve`` in all else. Returns the
+    workload's name."""
+    if module is not None:
+        shutil.copy(module, bench / "models" / f"{model_type}.py")
+    (bench / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
+    cell = common.load_json(bench / "workloads" / "tiny.serve.json")
+    cell["config"] = cfg["name"]
+    name = f"{cfg['name']}.serve"
+    (bench / "workloads" / f"{name}.json").write_text(json.dumps(cell))
+    return name
 
 
 def run_tiny(bench_dir, workload: str, seed: int, tmp, *, trace=False,

@@ -55,7 +55,7 @@ def test_control_fails_a_limit(bench, tmp_path, cell):
     from bench import run
     c = common.load_json(bench / "workloads" / f"{cell}.json")
     c["name"] = cell
-    cfg = common.load_json(bench / "configs" / f"{c['config']}.json")
+    cfg = common.load_config(bench, c["config"])
     drv = common.load_module(bench / "drivers" / f"{c['driver']}.py")
     run._use_cache(tmp_path / "cache")
     ctx = common.Context(t_start=time.perf_counter(), trace=False,
